@@ -197,6 +197,9 @@ class ModelConfig:
 # ---------------------------------------------------------------------------
 
 
+SMOKE_SUFFIX = "-smoke"   # registry name of an arch's reduced variant
+
+
 def reduced(cfg: ModelConfig) -> ModelConfig:
     """Same family, shrunk per contract: ≤2 layers, d_model ≤ 512, ≤4 experts."""
     d_model = min(cfg.d_model, 256)
@@ -215,7 +218,7 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         ssm = SSMConfig(d_state=16, d_conv=4, expand=2, head_dim=32, chunk_size=32)
     return dataclasses.replace(
         cfg,
-        name=cfg.name + "-smoke",
+        name=cfg.name + SMOKE_SUFFIX,
         num_layers=layers,
         d_model=d_model,
         num_heads=heads,
@@ -256,14 +259,18 @@ ASSIGNED_ARCHS = list(ARCH_MODULES)[:10]
 
 
 def get_config(name: str) -> ModelConfig:
-    if name not in ARCH_MODULES:
-        raise KeyError(f"unknown arch {name!r}; known: {sorted(ARCH_MODULES)}")
-    mod = importlib.import_module(f"repro.configs.{ARCH_MODULES[name]}")
-    return mod.CONFIG
+    """The published config of ``name``; ``<arch>-smoke`` names its
+    ``reduced()`` variant (the CPU-test size)."""
+    arch = name.removesuffix(SMOKE_SUFFIX)
+    if arch not in ARCH_MODULES:
+        raise KeyError(f"unknown arch {name!r}; known: {sorted(ARCH_MODULES)}"
+                       f" (each also as <arch>{SMOKE_SUFFIX})")
+    cfg = importlib.import_module(f"repro.configs.{ARCH_MODULES[arch]}").CONFIG
+    return reduced(cfg) if arch != name else cfg
 
 
 def get_smoke_config(name: str) -> ModelConfig:
-    return reduced(get_config(name))
+    return get_config(name + SMOKE_SUFFIX)
 
 
 def all_configs():

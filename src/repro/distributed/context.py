@@ -21,19 +21,7 @@ import contextvars
 import dataclasses
 from typing import Optional, Tuple
 
-import jax
 from jax.sharding import Mesh
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map``: top-level ``jax.shard_map`` on
-    newer jax, ``jax.experimental.shard_map`` (same contract) on the
-    pinned 0.4.x toolchain."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -2,10 +2,9 @@
 
 Two modes:
 
-  * closed-loop (default): boots an AgentServe engine for the reduced
-    variant of the selected architecture and serves a multi-agent
-    ToolBench-like workload, printing the per-policy report (the
-    paper's Fig-5-style output);
+  * closed-loop (default): boots an AgentServe engine for the selected
+    architecture and serves a multi-agent ToolBench-like workload,
+    printing the per-policy report (the paper's Fig-5-style output);
   * online (``--serve``): boots the asyncio gateway (DESIGN.md §6) and
     exposes a minimal stdlib HTTP/SSE front —
 
@@ -21,6 +20,11 @@ Two modes:
     drives it with an in-process asyncio client at an open-loop Poisson
     rate, prints the open-loop report row, and exits — the CI gateway
     smoke path.
+
+``--arch <id>`` builds the published config (random weights from a
+seed); ``--arch <id>-smoke`` builds its reduced CPU-test variant.  Each
+slot holds ``max_seq`` KV rows, sized from ``--workload`` at
+``--token-scale`` so no session runs past its slot.
 """
 from __future__ import annotations
 
@@ -29,16 +33,19 @@ import asyncio
 import dataclasses
 import json
 import sys
+import time
 from typing import Dict, List, Tuple
 
 import numpy as np
 
 import jax
 
-from repro.configs.base import get_smoke_config
+from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving.engine import EngineConfig, ServingEngine
-from repro.serving.gateway import AgentGateway, GatewayConfig, Rejected
+from repro.serving.gateway import (AgentGateway, GatewayConfig,
+                                   GatewayStopped, Rejected)
 from repro.serving.metrics import (OpenLoopReport, ServingReport,
                                    SLOThresholds, build_open_loop_report)
 from repro.serving.policies import PLANNERS, POLICIES
@@ -46,7 +53,7 @@ from repro.serving.telemetry import (parse_prometheus_text,
                                      reconstruct_latency,
                                      validate_trace_events)
 from repro.serving.workload import (SPECS, make_session, make_workload,
-                                    poisson_arrivals)
+                                    max_session_tokens, poisson_arrivals)
 
 
 # ---------------------------------------------------------------------------
@@ -56,7 +63,8 @@ from repro.serving.workload import (SPECS, make_session, make_workload,
 def _http_resp(status: int, body: bytes, ctype: str = "application/json",
                ) -> bytes:
     reason = {200: "OK", 404: "Not Found", 429: "Too Many Requests",
-              400: "Bad Request"}.get(status, "OK")
+              400: "Bad Request",
+              503: "Service Unavailable"}.get(status, "OK")
     return (f"HTTP/1.1 {status} {reason}\r\nContent-Type: {ctype}\r\n"
             f"Content-Length: {len(body)}\r\nConnection: close\r\n\r\n"
             ).encode() + body
@@ -66,7 +74,8 @@ def _json_resp(status: int, obj) -> bytes:
     return _http_resp(status, json.dumps(obj).encode())
 
 
-def _session_from_spec(spec: Dict, mcfg, default_token_scale: float):
+def _session_from_spec(spec: Dict, mcfg, default_token_scale: float,
+                       max_seq: int):
     """Build a scripted agent session from a client JSON spec:
     ``{"workload": "react", "seed": 7, "token_scale": 0.1,
     "slo_class": "interactive", "deadline_s": 30.0}``.  The session_id
@@ -74,7 +83,9 @@ def _session_from_spec(spec: Dict, mcfg, default_token_scale: float):
     ``--policy priority`` (interactive requests preempt batch cold
     prefills); ``deadline_s`` (relative seconds, optional) arms an
     engine-enforced SLO deadline — past it the session is aborted and
-    its stream ends with an ``event: aborted`` record."""
+    its stream ends with an ``event: aborted`` record.  A spec whose
+    longest possible session would not fit a ``max_seq`` slot is
+    refused."""
     workload = spec.get("workload", "react")
     if workload not in SPECS:
         raise ValueError(f"unknown workload {workload!r}")
@@ -83,6 +94,9 @@ def _session_from_spec(spec: Dict, mcfg, default_token_scale: float):
         raise ValueError(f"unknown slo_class {slo_class!r}")
     seed = int(spec.get("seed", 0))
     scale = float(spec.get("token_scale", default_token_scale))
+    if max_session_tokens(workload, scale) >= max_seq:
+        raise ValueError(f"{workload} sessions at token_scale {scale} can "
+                         f"outgrow the server's {max_seq}-token slots")
     rng = np.random.default_rng(seed)
     sess = make_session(-1, SPECS[workload], rng, mcfg.vocab_size,
                         token_scale=scale)
@@ -134,14 +148,20 @@ async def handle_connection(gateway: AgentGateway, mcfg,
                 spec = json.loads(body or b"{}")
                 if not isinstance(spec, dict):
                     raise ValueError("request body must be a JSON object")
-                sess = _session_from_spec(spec, mcfg, default_token_scale)
+                sess = _session_from_spec(spec, mcfg, default_token_scale,
+                                          gateway.engine.ecfg.max_seq)
                 deadline = spec.get("deadline_s")
                 deadline = None if deadline is None else float(deadline)
             except (ValueError, KeyError, TypeError) as e:
                 writer.write(_json_resp(400, {"error": str(e)}))
                 await writer.drain()
                 return
-            res = await gateway.submit(sess, deadline_s=deadline)
+            try:
+                res = await gateway.submit(sess, deadline_s=deadline)
+            except GatewayStopped as e:
+                writer.write(_json_resp(503, {"error": str(e)}))
+                await writer.drain()
+                return
             if isinstance(res, Rejected):
                 writer.write(_json_resp(429, {
                     "error": res.reason, "occupancy": res.occupancy}))
@@ -292,24 +312,42 @@ def _export_trace(engine, path: str) -> None:
 # gateway boot
 # ---------------------------------------------------------------------------
 
+def model_config(args):
+    """The served model: the published config of ``--arch`` (or its
+    reduced variant for ``<arch>-smoke``) in the requested KV layout."""
+    cfg = get_config(args.arch)
+    if args.kv_layout != "slab":
+        cfg = dataclasses.replace(cfg, kv_layout=args.kv_layout,
+                                  kv_page_size=args.kv_page_size)
+    return cfg
+
+
+def engine_config(args, cfg, *, max_wall_s: float = 300.0) -> EngineConfig:
+    """Engine shapes for the CLI.  ``max_seq`` holds the longest session
+    ``--workload`` can produce at ``--token-scale`` plus the scratch row
+    (DESIGN.md §3) and at least one largest resume bucket (warm-up
+    writes a whole bucket at offset 0), rounded up to the KV page."""
+    ecfg = EngineConfig(num_slots=max(args.agents + 2, 6),
+                        cycle_budget=160, granularity=16,
+                        control_interval_s=0.1, max_wall_s=max_wall_s,
+                        prefix_cache=args.prefix_cache,
+                        spec_k=args.spec_k,
+                        overlap_dispatch=(
+                            False if args.no_overlap
+                            else True if args.overlap else None))
+    need = max(max_session_tokens(args.workload, args.token_scale) + 1,
+               ecfg.b_max)
+    ecfg.max_seq = -(-need // cfg.kv_page_size) * cfg.kv_page_size
+    return ecfg
+
+
 def _build_engine(args, *, max_wall_s: float = 300.0,
                   ) -> Tuple[ServingEngine, object]:
     """One engine construction for both the closed-loop and online
     paths — they must not silently diverge in shapes/budget."""
-    cfg = get_smoke_config(args.arch)
-    if getattr(args, "kv_layout", "slab") != "slab":
-        cfg = dataclasses.replace(cfg, kv_layout=args.kv_layout,
-                                  kv_page_size=args.kv_page_size)
+    cfg = model_config(args)
     params = init_params(cfg, jax.random.PRNGKey(0))
-    ecfg = EngineConfig(num_slots=max(args.agents + 2, 6), max_seq=1024,
-                        cycle_budget=160, granularity=16,
-                        control_interval_s=0.1, max_wall_s=max_wall_s,
-                        prefix_cache=getattr(args, "prefix_cache", "radix"),
-                        spec_k=getattr(args, "spec_k", 0),
-                        overlap_dispatch=(
-                            False if getattr(args, "no_overlap", False)
-                            else True if getattr(args, "overlap", False)
-                            else None))
+    ecfg = engine_config(args, cfg, max_wall_s=max_wall_s)
     return ServingEngine(cfg, params, PLANNERS[args.policy], ecfg), cfg
 
 
@@ -343,11 +381,31 @@ async def _serve(args) -> int:
     return 0
 
 
-async def _serve_smoke(args) -> int:
+class SmokeFailed(RuntimeError):
+    """A ``--serve-smoke`` check failed."""
+
+
+def _check(ok: bool, msg: str) -> None:
+    if not ok:
+        raise SmokeFailed(msg)
+
+
+async def serve_smoke(args) -> Dict:
     """Boot the SSE server on an ephemeral port, drive it with an
     asyncio client cohort at an open-loop Poisson rate, and print the
-    open-loop report — end-to-end over real sockets."""
+    open-loop report — end-to-end over real sockets.  Raises
+    ``SmokeFailed`` when a check fails; returns the run's summary."""
+    t_boot = time.perf_counter()
     gateway, mcfg = build_gateway(args)
+    engine = gateway.engine
+    warmup_s = time.perf_counter() - t_boot
+    warmup_end = time.time()
+    print(f"engine: {mcfg.name} layers={mcfg.num_layers} "
+          f"d_model={mcfg.d_model} heads={mcfg.num_heads}/"
+          f"{mcfg.num_kv_heads} vocab={mcfg.vocab_size} "
+          f"kv_layout={mcfg.kv_layout} slots={engine.ecfg.num_slots} "
+          f"max_seq={engine.ecfg.max_seq} overlap={engine._overlap} "
+          f"warmup_s={warmup_s:.2f}", flush=True)
     await gateway.start()
     server = await asyncio.start_server(
         lambda r, w: handle_connection(gateway, mcfg, args.token_scale,
@@ -379,19 +437,24 @@ async def _serve_smoke(args) -> int:
     # /metrics parses as Prometheus text and the three stats views —
     # engine, gateway, HTTP — expose identical key sets
     m_status, m_text = await http_get_text(args.host, port, "/metrics")
-    assert m_status == 200, f"/metrics returned {m_status}"
-    samples = parse_prometheus_text(m_text)
-    assert samples, "/metrics served no samples"
     s_status, http_stats = await sse_get(args.host, port, "/stats")
-    assert s_status == 200, f"/stats returned {s_status}"
-    assert (set(http_stats) == set(gateway.stats())
-            == set(gateway.engine.stats())), "stats key drift"
-    print(f"/metrics: {len(samples)} samples, "
-          f"/stats: {len(http_stats)} keys (views agree)", flush=True)
-
     await gateway.stop(timeout_s=30.0)
     server.close()
     await server.wait_closed()
+
+    # an exception the gateway loop swallowed explains every later
+    # symptom (failed streams, missing tokens): report it first
+    errors = int(gateway.counters["engine_errors"])
+    _check(errors == 0, f"{errors} engine loop iterations failed; last:\n"
+                        f"{gateway.last_engine_error}")
+    _check(m_status == 200, f"/metrics returned {m_status}")
+    samples = parse_prometheus_text(m_text)
+    _check(bool(samples), "/metrics served no samples")
+    _check(s_status == 200, f"/stats returned {s_status}")
+    _check(set(http_stats) == set(gateway.stats()) == set(engine.stats()),
+           "stats key drift")
+    print(f"/metrics: {len(samples)} samples, "
+          f"/stats: {len(http_stats)} keys (views agree)", flush=True)
 
     ok = statuses.count(200)
     shed = statuses.count(429)
@@ -405,16 +468,23 @@ async def _serve_smoke(args) -> int:
         args.policy, done, wall, args.rate, rejected=shed,
         thresholds=SLOThresholds(ttft_s=10.0, tpot_s=2.0),
         aborted_sessions=list(gateway.failed_sessions))
-    rep.tokens_per_model_call = gateway.engine.tokens_per_model_call()
+    rep.tokens_per_model_call = engine.tokens_per_model_call()
     print(OpenLoopReport.HEADER)
     print(rep.row(), flush=True)
-    assert ok + shed == args.agents, "every request must resolve"
-    assert ok > 0 and len(all_events) > 0, "no tokens streamed"
-    assert len(done) == ok, "every admitted session must finish"
+    _check(ok + shed == args.agents,
+           f"every request must resolve: statuses {sorted(statuses)}")
+    _check(ok > 0 and len(all_events) > 0, "no tokens streamed")
+    _check(len(done) == ok, f"{ok} sessions admitted, {len(done)} finished")
+    short = [s.session_id for s in done
+             if s.output_tokens() != sum(t.decode_len for t in s.turns)]
+    _check(not short, f"sessions {short} emitted a wrong token count")
+    overruns = int(engine.hotpath_stats["capacity_overruns"])
+    _check(overruns == 0, f"{overruns} KV capacity overruns "
+                          f"(max_seq={engine.ecfg.max_seq})")
 
     # timeline export + the acceptance cross-check: per-session spans
     # must reconstruct TTFT/TPOT within 1% of metrics.py's values
-    tracer = gateway.engine.telemetry.tracer
+    tracer = engine.telemetry.tracer
     if tracer is not None and done:
         from repro.serving.metrics import collect_tpots, collect_ttfts
         span_ttfts, span_tpot = reconstruct_latency(tracer.spans)
@@ -422,17 +492,24 @@ async def _serve_smoke(args) -> int:
         m_tpots = collect_tpots(done)
         if m_ttfts:
             a, b = float(np.mean(span_ttfts)), float(np.mean(m_ttfts))
-            assert abs(a - b) <= 0.01 * b, f"span TTFT {a} vs {b}"
+            _check(abs(a - b) <= 0.01 * b, f"span TTFT {a} vs {b}")
         if m_tpots:
             a, b = span_tpot, float(np.mean(m_tpots))
-            assert abs(a - b) <= 0.01 * b, f"span TPOT {a} vs {b}"
-        assert tracer.open_span_count() == 0, \
-            f"leaked spans: {tracer.open_spans()}"
+            _check(abs(a - b) <= 0.01 * b, f"span TPOT {a} vs {b}")
+        _check(tracer.open_span_count() == 0,
+               f"leaked spans: {tracer.open_spans()}")
         print(f"span reconstruction OK: {len(span_ttfts)} TTFTs, "
               f"mean TPOT {span_tpot * 1e3:.2f}ms within 1% of metrics",
               flush=True)
-    _export_trace(gateway.engine, args.trace_out)
-    return 0
+    _export_trace(engine, args.trace_out)
+    return {"model": mcfg.name, "num_layers": mcfg.num_layers,
+            "d_model": mcfg.d_model, "kv_layout": mcfg.kv_layout,
+            "max_seq": engine.ecfg.max_seq, "overlap": engine._overlap,
+            "warmup_s": warmup_s, "warmup_end": warmup_end,
+            "wall_s": wall, "streams_ok": ok, "shed": shed,
+            "tokens_streamed": len(all_events),
+            "output_tokens": sum(s.output_tokens() for s in done),
+            "capacity_overruns": overruns, "engine_errors": errors}
 
 
 # ---------------------------------------------------------------------------
@@ -456,9 +533,11 @@ def _closed_loop(args) -> int:
     return 0
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="smollm-360m")
+    ap.add_argument("--arch", default="smollm-360m",
+                    help="registry id: the published config; append "
+                         "-smoke for the reduced CPU-test variant")
     ap.add_argument("--policy", default="agentserve",
                     choices=sorted(PLANNERS))
     ap.add_argument("--agents", type=int, default=4)
@@ -512,10 +591,15 @@ def main(argv=None) -> int:
                     help="write the run's span timeline as Chrome/"
                          "Perfetto trace_event JSON to this path "
                          "(load in ui.perfetto.dev or chrome://tracing)")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def main(argv=None) -> int:
+    enable_compile_cache()
+    args = build_parser().parse_args(argv)
     if args.serve_smoke:
-        return asyncio.run(_serve_smoke(args))
+        asyncio.run(serve_smoke(args))
+        return 0
     if args.serve:
         return asyncio.run(_serve(args))
     return _closed_loop(args)

@@ -1,9 +1,9 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
-Runs real training steps on the local device(s).  For the production
-mesh this is the same ``make_train_step`` the dry-run lowers; locally it
-trains the reduced variant of the selected architecture on the synthetic
-corpus (the end-to-end driver of examples/train_slm.py).
+Runs real training steps on the local device(s) with the same
+``make_train_step`` the dry-run lowers, on the synthetic corpus.
+``--arch <id>`` trains the published config; ``--arch <id>-smoke`` its
+reduced CPU-test variant.
 """
 from __future__ import annotations
 
@@ -14,7 +14,8 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import get_config, get_smoke_config
+from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.models.common import count_params
 from repro.training.checkpoint import load_checkpoint, save_checkpoint
@@ -24,11 +25,11 @@ from repro.training.train_step import make_train_step
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="smollm-360m")
-    ap.add_argument("--full-config", action="store_true",
-                    help="use the full (production) config instead of the "
-                         "reduced variant — requires real accelerators")
+    ap.add_argument("--arch", default="smollm-360m",
+                    help="registry id: the published config; append "
+                         "-smoke for the reduced CPU-test variant")
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -38,8 +39,7 @@ def main(argv=None) -> int:
     ap.add_argument("--resume", default="")
     args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch) if args.full_config \
-        else get_smoke_config(args.arch)
+    cfg = get_config(args.arch)
     print(f"# arch={cfg.name} family={cfg.family} layers={cfg.num_layers} "
           f"d_model={cfg.d_model}")
     params = init_params(cfg, jax.random.PRNGKey(0))

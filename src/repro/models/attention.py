@@ -463,9 +463,8 @@ def decode_attention_seqpar(q, k_new, v_new, k_cache, v_cache, lengths,
         in_specs += [specs_kv, specs_kv, specs_q, specs_q]
         args += [k_scale, v_scale] + list(new_scales)
         out_specs = out_specs + (specs_kv, specs_kv)
-    from repro.distributed.context import shard_map
-    fn = shard_map(local, mesh=spmd.mesh, in_specs=tuple(in_specs),
-                   out_specs=out_specs)
+    fn = jax.shard_map(local, mesh=spmd.mesh, in_specs=tuple(in_specs),
+                       out_specs=out_specs)
     return fn(*args)
 
 

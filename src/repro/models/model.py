@@ -223,8 +223,29 @@ def _scan_groups(params, x, cfg: ModelConfig, *, mode: str, positions,
                                    (xs2, None))
         return x, aux, None
 
-    (x, aux), new_cache = jax.lax.scan(body, (x, aux0),
-                                       (params["groups"], cache))
+    if cache is None:
+        (x, aux), _ = jax.lax.scan(body, (x, aux0), (params["groups"], None))
+        return x, aux, None
+
+    # the stacked cache rides in the carry and each group's slice is
+    # written back in place: stacking new slices as scan outputs would
+    # build a second whole cache per call (per step inside a megastep
+    # scan), which at published widths exceeds a 16 GB chip
+    def body_inplace(carry, xs):
+        h, aux, full = carry
+        gparams, g = xs
+        gcache = jax.tree.map(
+            lambda c: jax.lax.dynamic_index_in_dim(c, g, 0, keepdims=False),
+            full)
+        (h, aux), new_g = body((h, aux), (gparams, gcache))
+        full = jax.tree.map(
+            lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, g, 0),
+            full, new_g)
+        return (h, aux, full), None
+
+    (x, aux, new_cache), _ = jax.lax.scan(
+        body_inplace, (x, aux0, cache),
+        (params["groups"], jnp.arange(G, dtype=jnp.int32)))
     return x, aux, new_cache
 
 

@@ -227,9 +227,8 @@ def _gmm_shard_map(params, x2d, weights, idx, *, moe: MoEConfig, act: str,
             out = one((x_l, w_l, i_l))
         return jax.lax.psum(out, tp)
 
-    from repro.distributed.context import shard_map
-    fn = shard_map(local, mesh=spmd.mesh,
-                   in_specs=(ffn_specs, P(dp, None), P(dp, None),
-                             P(dp, None)),
-                   out_specs=P(dp, None))
+    fn = jax.shard_map(local, mesh=spmd.mesh,
+                       in_specs=(ffn_specs, P(dp, None), P(dp, None),
+                                 P(dp, None)),
+                       out_specs=P(dp, None))
     return fn(ffn_params, x2d, weights, idx)

@@ -703,12 +703,15 @@ class ServingEngine:
 
     # ------------------------------------------------------------------
     # executables & warmup
+    #
+    # Cache-donating executables warm on the pool cache itself and hand
+    # the result back to the pool: with every lane inactive they write
+    # only the scratch row/page, and resume shapes write rows of slots
+    # that are still free (fenced by length 0; SSM state is zeroed at
+    # alloc).  A sacrificial copy would keep a second whole cache alive
+    # at the warm-up peak, which a 16 GB chip cannot hold at published
+    # widths.
     # ------------------------------------------------------------------
-    def _cache_clone(self):
-        """A sacrificial copy of the pool cache for warming donating
-        executables (the donated input is consumed by the call)."""
-        return jax.tree.map(jnp.copy, self.pool.cache)
-
     def _bt(self) -> Tuple:
         """Trailing block-table args for paged executables (empty under
         the slab layout, so call sites can splat unconditionally)."""
@@ -775,10 +778,10 @@ class ServingEngine:
             fn = jax.jit(functools.partial(raw_m, num_steps=level),
                          donate_argnums=(1,))
         B = self.ecfg.num_slots
-        toks, _, _, _ = fn(self.params, self._cache_clone(),
-                           jnp.zeros((B,), jnp.int32),
-                           jnp.zeros((B,), jnp.int32),
-                           jnp.zeros((B,), bool), *self._bt())
+        toks, _, self.pool.cache, _ = fn(self.params, self.pool.cache,
+                                         jnp.zeros((B,), jnp.int32),
+                                         jnp.zeros((B,), jnp.int32),
+                                         jnp.zeros((B,), bool), *self._bt())
         jax.block_until_ready(toks)
         return {"steps": level, "fn": fn}
 
@@ -793,11 +796,11 @@ class ServingEngine:
                                            ngram=self.ecfg.spec_ngram),
                          donate_argnums=(1,))
         B = self.ecfg.num_slots
-        toks, *_ = fn(self.params, self._cache_clone(),
-                      jnp.zeros((B, self.ecfg.max_seq), jnp.int32),
-                      jnp.zeros((B,), jnp.int32),
-                      jnp.zeros((B,), jnp.int32),
-                      jnp.zeros((B,), bool), *self._bt())
+        toks, _, _, _, self.pool.cache, _ = fn(
+            self.params, self.pool.cache,
+            jnp.zeros((B, self.ecfg.max_seq), jnp.int32),
+            jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
+            jnp.zeros((B,), bool), *self._bt())
         jax.block_until_ready(toks)
         return {"draft": level, "fn": fn}
 
@@ -812,8 +815,8 @@ class ServingEngine:
         if (m, bucket) in self._warmed_packs:
             return      # resume and cold-pack grids share (M, bucket) shapes
         self._warmed_packs.add((m, bucket))
-        lg, _ = self._ex.resume(
-            self.params, self._cache_clone(),
+        lg, self.pool.cache = self._ex.resume(
+            self.params, self.pool.cache,
             jnp.zeros((m, bucket), jnp.int32),
             jnp.arange(m, dtype=jnp.int32),
             jnp.zeros((m,), jnp.int32),
@@ -826,9 +829,9 @@ class ServingEngine:
         lg, _ = self._decode_fn(self.params, self.pool.cache, zeros_b,
                                 zeros_b, *self._bt())
         jax.block_until_ready(lg)
-        nt, _, _ = self._ex.fused(self.params, self._cache_clone(), zeros_b,
-                                  zeros_b, jnp.zeros((B,), bool),
-                                  *self._bt())
+        nt, self.pool.cache, _ = self._ex.fused(
+            self.params, self.pool.cache, zeros_b, zeros_b,
+            jnp.zeros((B,), bool), *self._bt())
         jax.block_until_ready(nt)
         if self.policy.resume_to_decode_queue:
             for m in self._resume_levels:

@@ -43,6 +43,8 @@ import collections
 import dataclasses
 import enum
 import itertools
+import logging
+import traceback
 from typing import AsyncIterator, Awaitable, Callable, Deque, Dict, List, \
     Optional, Tuple, Union
 
@@ -57,6 +59,13 @@ from repro.serving.telemetry import RegistryDict
 # for the *next* turn's prefill (a real tool's output); None keeps the
 # scripted tokens.
 ToolFn = Callable[[Session, int], Awaitable[Optional[np.ndarray]]]
+
+log = logging.getLogger(__name__)
+
+
+class GatewayStopped(RuntimeError):
+    """``submit`` after the reactor loop died (``max_engine_errors``
+    consecutive failures): nothing would ever serve the session."""
 
 
 @dataclasses.dataclass
@@ -188,6 +197,11 @@ class AgentGateway:
         self._waiters = 0
         self._tool_tasks: set = set()
         self._rng = np.random.default_rng(self.cfg.seed)  # backoff jitter
+        # formatted traceback of the last exception that escaped a loop
+        # iteration (counted in engine_errors) — kept so a device fault
+        # (OOM, compile failure) has a visible cause, not only failed
+        # sessions
+        self.last_engine_error: Optional[str] = None
         # finished sessions, retained (bounded) for open-loop reporting
         # — the engine/reactor detach them at session_end
         self.completed_sessions: Deque[Session] = collections.deque(
@@ -289,6 +303,10 @@ class AgentGateway:
         ``deadline_s`` (relative seconds, overrides the config default)
         arms an engine-enforced SLO deadline: past it the session is
         aborted and its stream ends with an error event."""
+        if self._task is not None and self._task.done():
+            raise GatewayStopped(
+                f"gateway loop stopped; last engine error:\n"
+                f"{self.last_engine_error}")
         self._kv_pressure_gate()
         occ = self.occupancy()
         if not self.gate.check(occ) and self.cfg.admission == "queue":
@@ -351,6 +369,8 @@ class AgentGateway:
                 raise
             except Exception:
                 self.counters["engine_errors"] += 1
+                self.last_engine_error = traceback.format_exc()
+                log.exception("engine loop iteration failed")
                 errors_in_row += 1
                 if errors_in_row >= cfg.max_engine_errors:
                     self._fail_all_live("engine_error")

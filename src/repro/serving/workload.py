@@ -99,6 +99,24 @@ def make_session(session_id: int, spec: WorkloadSpec, rng: np.random.Generator,
                    shared_prefix_len=shared_len)
 
 
+def max_session_tokens(workload: str, token_scale: float = 1.0) -> int:
+    """Upper bound on the KV rows one session of ``workload`` can hold
+    at ``token_scale``: the longest cold prompt ``make_session`` draws
+    (plus the ``cold_len // 8`` user tail behind a shared system
+    prompt), then the most turns, each with the longest resume and
+    decode.  Engines size ``max_seq`` from this so no session runs past
+    its slot."""
+    spec = SPECS[workload]
+
+    def scale(n):
+        return max(1, int(round(n * token_scale)))
+
+    cold = scale(spec.cold_range[1] - 1)       # rng.integers: high exclusive
+    turns = spec.turns_range[1] - 1
+    return (cold + max(cold // 8, 1) + (turns - 1) * scale(spec.resume_range[1])
+            + turns * scale(spec.decode_range[1]))
+
+
 def make_workload(num_sessions: int, *, workload: str = "react",
                   vocab_size: int = 512, token_scale: float = 1.0,
                   num_system_prompts: int = 1, seed: int = 0,

@@ -6,6 +6,7 @@ greedy oracle (tests/_serving_util.py), so concurrency bugs that
 corrupt KV state cannot hide behind 'all sessions finished'."""
 import asyncio
 import json
+import logging
 
 import jax
 import numpy as np
@@ -16,7 +17,8 @@ from repro.configs.base import ModelConfig
 from repro.core.admission import WatermarkGate
 from repro.models import init_params
 from repro.serving.engine import EngineConfig, ServingEngine
-from repro.serving.gateway import (AgentGateway, GatewayConfig, Rejected,
+from repro.serving.gateway import (AgentGateway, GatewayConfig,
+                                   GatewayStopped, Rejected,
                                    drive_open_loop)
 from repro.serving.metrics import (OpenLoopReport, SLOThresholds,
                                    build_open_loop_report)
@@ -286,6 +288,65 @@ def test_tool_fn_can_replace_next_turn_prefill(tiny_params):
     streams = events_by_session([ev for _, ev in run.events])
     want = oracle_streams(TINY, tiny_params, [s], num_slots=4, max_seq=512)
     assert streams[s.session_id] == want[s.session_id]
+
+
+def test_swallowed_engine_error_is_kept_and_logged(tiny_params, caplog):
+    """An exception escaping one loop iteration is counted, logged with
+    its traceback and kept on the gateway; the loop carries on and the
+    sessions still finish."""
+    eng = _engine(tiny_params)
+    gw = AgentGateway(eng, GatewayConfig(high_watermark=32))
+    step, calls = gw.reactor.step, []
+
+    def flaky_step():
+        calls.append(1)
+        if len(calls) in (2, 3):
+            raise RuntimeError("injected device fault")
+        return step()
+
+    gw.reactor.step = flaky_step
+    with caplog.at_level(logging.ERROR, logger="repro.serving.gateway"):
+        run = _drive(gw, _sessions(2, rate=8.0))
+    assert len(run.completed) == 2
+    assert gw.counters["engine_errors"] == 2
+    assert "injected device fault" in gw.last_engine_error
+    assert gw.last_engine_error.startswith("Traceback")
+    assert "injected device fault" in caplog.text
+
+
+def test_dead_gateway_loop_refuses_new_sessions(tiny_params):
+    """After max_engine_errors consecutive failures the loop exits; a
+    later submit raises with the cause instead of queueing a session
+    nothing will ever serve, and the HTTP front answers 503."""
+    from repro.launch.serve import handle_connection, sse_submit
+
+    eng = _engine(tiny_params)
+    gw = AgentGateway(eng, GatewayConfig(high_watermark=32,
+                                         max_engine_errors=2))
+
+    def broken_step():
+        raise RuntimeError("device lost")
+
+    gw.reactor.step = broken_step
+
+    async def go():
+        await gw.start()
+        await asyncio.wait_for(asyncio.shield(gw._task), 30.0)
+        with pytest.raises(GatewayStopped, match="device lost"):
+            await gw.submit(_sessions(1)[0])
+        server = await asyncio.start_server(
+            lambda r, w: handle_connection(gw, TINY, 0.0625, r, w),
+            "127.0.0.1", 0)
+        port = server.sockets[0].getsockname()[1]
+        status, events = await sse_submit("127.0.0.1", port, {"seed": 1})
+        server.close()
+        await server.wait_closed()
+        await gw.stop(timeout_s=5.0)
+        return status, events
+
+    status, events = asyncio.run(go())
+    assert status == 503 and events == []
+    assert gw.counters["engine_errors"] == 2
 
 
 # ---------------------------------------------------------------------------
