@@ -89,7 +89,7 @@ from repro.serving.metrics import ServingReport, SLOThresholds, build_report
 from repro.serving.policies import PolicySpec, make_planner
 from repro.serving.reactor import TokenEvent
 from repro.serving.request import Session, SessionState
-from repro.serving.telemetry import RegistryDict, Telemetry
+from repro.serving.telemetry import RegistryDict, Telemetry, span
 
 
 @dataclasses.dataclass
@@ -607,10 +607,23 @@ class ServingEngine:
                     "parks": "engine_parks",
                     "unparks": "engine_unparks"},
             help_prefix="engine hot-path counter: ")
-        self._h_ttft = reg.histogram(
-            "ttft_s", help="request submission -> first token (s)")
-        self._h_tpot = reg.histogram(
-            "tpot_s", help="inter-token gap within decode bursts (s)")
+        # per-request stages (Session.staged_s .. first_token_s)
+        self._h_gwait = reg.histogram(
+            "gateway_wait_s",
+            help="gateway staging (submit entry / tool return) -> op "
+                 "applied to the engine (s)")
+        self._h_pqueue = reg.histogram(
+            "prefill_queue_s",
+            help="admission -> first device call carrying the "
+                 "request's prompt (s)")
+        self._h_prun = reg.histogram(
+            "prefill_run_s",
+            help="first device call carrying the prompt -> first token "
+                 "read by the host (s)")
+        self._h_hold = reg.histogram(
+            "token_hold_s",
+            help="decode token's interpolated stamp -> its host read at "
+                 "the flush, one weighted observation per flush (s)")
         self._h_gap = reg.histogram(
             "dispatch_gap_s",
             help="host time between consecutive decode submissions, "
@@ -882,11 +895,14 @@ class ServingEngine:
             self._history[sess.slot, L:L + take] = toks[:take]
             self._hist_dirty = True
         self._prepare_append(sess.slot, take)
-        logits, new_cache = fn(
-            self.params, self.pool.cache,
-            jnp.asarray(toks[None], jnp.int32),
-            jnp.int32(sess.slot), jnp.int32(self.pool.lengths[sess.slot]),
-            jnp.int32(take - 1), *self._bt())
+        self._mark_dispatched(sess, self._clock())
+        with span("engine.dispatch.prefill"):
+            logits, new_cache = fn(
+                self.params, self.pool.cache,
+                jnp.asarray(toks[None], jnp.int32),
+                jnp.int32(sess.slot),
+                jnp.int32(self.pool.lengths[sess.slot]),
+                jnp.int32(take - 1), *self._bt())
         self._note_prefill_dispatch([self.pool.lengths[sess.slot]], shape_len)
         self._cycle_prefill_tokens += take
         self.pool.cache = new_cache
@@ -895,7 +911,16 @@ class ServingEngine:
         sess.cached_len = int(self.pool.lengths[sess.slot])
         self._maybe_register_prefix(sess)
         if sess.remaining_prefill == 0:
-            self._finish_prefill(sess, np.asarray(logits))
+            with span("engine.block"):
+                last_logits = np.asarray(logits)
+            self._finish_prefill(sess, last_logits)
+
+    def _mark_dispatched(self, sess: Session, t: float) -> None:
+        """Stamp the first device call that carries any of the current
+        request's prompt — once per request, not once per chunk."""
+        if len(sess.dispatched_s) < len(sess.request_arrivals):
+            sess.dispatched_s.append(t)
+            self._h_pqueue.observe(max(0.0, t - sess.request_arrivals[-1]))
 
     def _note_prefill_dispatch(self, cached_lens, shape_len: int,
                                cold_pack: int = 0) -> None:
@@ -941,7 +966,7 @@ class ServingEngine:
         sess.first_token_s.append(now)
         sess.token_times_s.append(now)
         sess.decoded = 1
-        self._h_ttft.observe(now - sess.arrival_s)
+        self._h_prun.observe(now - sess.dispatched_s[-1])
         tr = self.telemetry.tracer
         if tr is not None:
             # DECODE span start == first-token timestamp; ``tokens``
@@ -954,15 +979,18 @@ class ServingEngine:
         self._after_token(sess, now)
 
     def _emit(self, sess: Session, token, t: float, index: int,
-              first: bool = False, turn_end: bool = False) -> None:
+              first: bool = False, turn_end: bool = False,
+              t_emit: Optional[float] = None) -> None:
         """Record one emitted token as a reactor event (drained by
         ``step()``).  Must run *before* ``_after_token`` advances
-        ``turn_idx`` so the event names the turn that produced it."""
+        ``turn_idx`` so the event names the turn that produced it.
+        ``t_emit`` is when the host read the token (default ``t``)."""
         self._events.append(TokenEvent(
             session_id=sess.session_id, token=int(token), t=t,
             turn_idx=sess.turn_idx, index=index, first=first,
             turn_end=turn_end,
-            session_end=turn_end and sess.turn_idx + 1 >= len(sess.turns)))
+            session_end=turn_end and sess.turn_idx + 1 >= len(sess.turns),
+            t_emit=t_emit))
 
     # ------------------------------------------------------------------
     # decode stream (device-resident)
@@ -985,11 +1013,16 @@ class ServingEngine:
         restores) — every such event passes through a flush, so the host
         mirrors are exact at this point.  The spec token-history mirror
         re-uploads whenever a host-side write dirtied it (prefill
-        chunks, unparks), independently of membership."""
+        chunks, unparks), independently of membership.
+
+        The mirrors are uploaded as copies: ``jnp.asarray`` may read a
+        host buffer only when the transfer or the step that uses it
+        runs, and the host advances ``pool.lengths`` and ``_history``
+        in place right after dispatch."""
         ids = [s.session_id for s in active]
         if not self._dev_dirty and ids == self._dev_ids:
             if self._spec_on and self._hist_dirty:
-                self._dev_history = jnp.asarray(self._history)
+                self._dev_history = jnp.asarray(self._history.copy())
                 self._hist_dirty = False
             return
         if self._window_steps:
@@ -1002,9 +1035,9 @@ class ServingEngine:
             mask[s.slot] = True
         self._dev_tokens = jnp.asarray(tokens)
         self._dev_mask = jnp.asarray(mask)
-        self._dev_lengths = jnp.asarray(self.pool.lengths)
+        self._dev_lengths = jnp.asarray(self.pool.lengths.copy())
         if self._spec_on:
-            self._dev_history = jnp.asarray(self._history)
+            self._dev_history = jnp.asarray(self._history.copy())
             self._hist_dirty = False
         self._dev_ids = ids
         self._dev_dirty = False
@@ -1086,16 +1119,18 @@ class ServingEngine:
         if self._last_dispatch_t is not None:
             self._observe_gap(t_disp)
         if exe is not None:
-            step_toks, nt, nc, nl = exe(self.params, self.pool.cache,
-                                        self._dev_tokens, self._dev_lengths,
-                                        self._dev_mask, *self._bt())
+            with span("engine.dispatch.decode"):
+                step_toks, nt, nc, nl = exe(
+                    self.params, self.pool.cache, self._dev_tokens,
+                    self._dev_lengths, self._dev_mask, *self._bt())
             self._window_toks.append(step_toks)      # [K, B] per-step ids
             self.hotpath_stats["megasteps"] += 1
             self.hotpath_stats["mega_tokens"] += K * len(active)
         else:
-            nt, nc, nl = self._ex.fused(self.params, self.pool.cache,
-                                        self._dev_tokens, self._dev_lengths,
-                                        self._dev_mask, *self._bt())
+            with span("engine.dispatch.decode"):
+                nt, nc, nl = self._ex.fused(
+                    self.params, self.pool.cache, self._dev_tokens,
+                    self._dev_lengths, self._dev_mask, *self._bt())
             self._window_toks.append(nt)             # [B] one-step ids
             self.hotpath_stats["fused_steps"] += 1
         self._dev_tokens, self._dev_lengths = nt, nl
@@ -1162,10 +1197,11 @@ class ServingEngine:
         t_disp = self._clock()
         if self._last_dispatch_t is not None:
             self._observe_gap(t_disp)
-        toks, acc, nt, nh, nc, nl = exe(
-            self.params, self.pool.cache, self._dev_history,
-            self._dev_tokens, self._dev_lengths, self._dev_mask,
-            *self._bt())
+        with span("engine.dispatch.decode"):
+            toks, acc, nt, nh, nc, nl = exe(
+                self.params, self.pool.cache, self._dev_history,
+                self._dev_tokens, self._dev_lengths, self._dev_mask,
+                *self._bt())
         self._window_spec.append((toks, acc, K))
         self._dev_tokens, self._dev_lengths = nt, nl
         self._dev_history = nh
@@ -1233,7 +1269,8 @@ class ServingEngine:
         n = rec["steps"]
         sessions = rec["sessions"]
         t_wait = self._clock()
-        jax.block_until_ready(rec["toks"][-1])
+        with span("engine.block"):
+            jax.block_until_ready(rec["toks"][-1])
         now = self._clock()
         self._h_devwait.observe(now - t_wait)
         self._cycle_block_s += now - t_wait
@@ -1243,9 +1280,9 @@ class ServingEngine:
             self.scheduler.record_decode_step(now - t0, steps=n)
             ts = [t0 + (now - t0) * (i + 1) / n for i in range(n)]
             # one weighted observation per flush, not one per token:
-            # the window-mean gap for each of the window's n steps
+            # the window-mean hold of each of the window's n steps
             # across every session in the window
-            self._h_tpot.observe((now - t0) / n, n * len(sessions))
+            self._h_hold.observe(now - sum(ts) / n, n * len(sessions))
         else:
             ts = [now] * n
         B = self.ecfg.num_slots
@@ -1261,7 +1298,7 @@ class ServingEngine:
             dlen = s.current_turn.decode_len
             for i in range(n):
                 self._emit(s, step_toks[i, s.slot], ts[i], index=base + i,
-                           turn_end=base + i + 1 >= dlen)
+                           turn_end=base + i + 1 >= dlen, t_emit=now)
             if self._spec_on:
                 # plain windows also feed the proposer history: the
                 # window's inputs were last_token-before plus every
@@ -1294,21 +1331,22 @@ class ServingEngine:
         detached double-buffer window first, then the live window —
         plain (uniform n steps) or speculative (variable accepted
         counts)."""
-        self._drain_completed()
-        for k, v in self._prefill_pending.items():
-            self.hotpath_stats[k] += v
-            self._prefill_pending[k] = 0
-        if self._window_steps == 0:
-            return
-        if self._window_mode == "spec":
-            self._flush_spec_window()
-            return
-        sessions = self._window_sessions
-        rec = self._take_window()
-        now = self._process_window(rec)
-        self._window_t0 = now
-        for s in sessions:
-            self._after_token(s, now)
+        with span("engine.flush"):
+            self._drain_completed()
+            for k, v in self._prefill_pending.items():
+                self.hotpath_stats[k] += v
+                self._prefill_pending[k] = 0
+            if self._window_steps == 0:
+                return
+            if self._window_mode == "spec":
+                self._flush_spec_window()
+                return
+            sessions = self._window_sessions
+            rec = self._take_window()
+            now = self._process_window(rec)
+            self._window_t0 = now
+            for s in sessions:
+                self._after_token(s, now)
 
     def _flush_spec_window(self) -> None:
         """Host sync of a speculative window: read each dispatch's
@@ -1325,7 +1363,8 @@ class ServingEngine:
         self._window_pending = 0
         self._window_mode = "plain"
         t_wait = self._clock()
-        jax.block_until_ready(self._dev_tokens)
+        with span("engine.block"):
+            jax.block_until_ready(self._dev_tokens)
         now = self._clock()
         self._h_devwait.observe(now - t_wait)
         self._cycle_block_s += now - t_wait
@@ -1372,13 +1411,13 @@ class ServingEngine:
             if t0 is not None and now > t0:
                 ts = [t0 + (now - t0) * (i + 1) / total
                       for i in range(total)]
-                self._h_tpot.observe((now - t0) / total, total)
+                self._h_hold.observe(now - sum(ts) / total, total)
             else:
                 ts = [now] * total
             s.token_times_s.extend(ts)
             for i, tok in enumerate(emitted):
                 self._emit(s, tok, ts[i], index=base + i,
-                           turn_end=base + i + 1 >= dlen)
+                           turn_end=base + i + 1 >= dlen, t_emit=now)
             self._after_token(s, now)
         if t0 is not None and now > t0 and totals:
             # controller TPOT: the window's per-token pace at the mean
@@ -1500,8 +1539,9 @@ class ServingEngine:
         s.request_arrivals.append(now)
         # queue delay: how long the request sat ready (slot/backpressure
         # wait) before admission — the open-loop breakdown metric
-        s.queue_delays_s.append(max(0.0, now - s.ready_s)
-                                if np.isfinite(s.ready_s) else 0.0)
+        ready = s.ready_s if np.isfinite(s.ready_s) else now
+        s.readied_s.append(ready)
+        s.queue_delays_s.append(max(0.0, now - ready))
         s.state = SessionState.PREFILLING
         tr = self.telemetry.tracer
         if tr is not None:
@@ -1658,10 +1698,15 @@ class ServingEngine:
             # their slots (consistent — those appends just retry free)
             e.session_id = s.session_id
             raise
-        logits, new_cache = self._ex.resume(
-            self.params, self.pool.cache, jnp.asarray(toks),
-            jnp.asarray(slots), jnp.asarray(lens), jnp.asarray(logit_idx),
-            *self._bt())
+        t_disp = self._clock()
+        for _, s in jobs:
+            self._mark_dispatched(s, t_disp)
+        with span("engine.dispatch.prefill" if cold_pack
+                  else "engine.dispatch.resume"):
+            logits, new_cache = self._ex.resume(
+                self.params, self.pool.cache, jnp.asarray(toks),
+                jnp.asarray(slots), jnp.asarray(lens),
+                jnp.asarray(logit_idx), *self._bt())
         self.pool.cache = new_cache
         self._note_prefill_dispatch(lens, bucket, cold_pack=cold_pack)
         self._cycle_prefill_tokens += sum(takes)
@@ -1679,7 +1724,8 @@ class ServingEngine:
             self._maybe_register_prefix(s)
             if s.remaining_prefill == 0:
                 if np_logits is None:
-                    np_logits = np.asarray(logits)
+                    with span("engine.block"):
+                        np_logits = np.asarray(logits)
                 self._finish_prefill(s, np_logits[i])
             else:
                 unfinished.append((job, s))
@@ -1921,6 +1967,10 @@ class ServingEngine:
         the sampled-cadence decode flush.  Returns the token events this
         cycle emitted (``last_step_did_work`` tells idle-sleep callers
         whether anything was dispatched)."""
+        with span("engine.step"):
+            return self._step()
+
+    def _step(self) -> List[TokenEvent]:
         ecfg = self.ecfg
         now = self._clock()
 
@@ -1942,7 +1992,8 @@ class ServingEngine:
 
         # ---- plan → execute ---------------------------------------
         view = self.snapshot(now)
-        plan = self.planner.plan(view)
+        with span("engine.plan"):
+            plan = self.planner.plan(view)
         # stamp the telemetry/journal correlation id — but only on the
         # -1 sentinel: a ReplayPlanner hands back recorded plans whose
         # original ids must survive so replayed timelines diff cleanly
@@ -1959,7 +2010,8 @@ class ServingEngine:
         self._cycle_block_s = 0.0
         t_host0 = time.perf_counter()
         try:
-            outcome = self.dispatcher.execute(plan, now)
+            with span("engine.execute"):
+                outcome = self.dispatcher.execute(plan, now)
         except SessionFault as f:
             # engine-level quarantine: the fault names exactly one
             # session (checks run *before* device dispatch, so no
@@ -2028,7 +2080,15 @@ class ServingEngine:
             raise ValueError(
                 f"session {session_id} not in TOOL_WAIT ({s.state})")
         s.state = SessionState.TOOL_CALL
-        s.ready_s = self._clock()
+        self.mark_ready(s, self._clock())
+
+    def mark_ready(self, s: Session, t: float) -> None:
+        """Stamp a request ready: its submit or resume op has reached
+        the engine.  A request the gateway staged observes the wait
+        between staging and now."""
+        s.ready_s = t
+        if len(s.staged_s) > len(s.request_arrivals):
+            self._h_gwait.observe(max(0.0, t - s.staged_s[-1]))
 
     def park_session(self, session_id: int) -> None:
         """Release a TOOL_WAIT session's KV slot under pressure: the

@@ -53,7 +53,7 @@ import numpy as np
 from repro.core.admission import WatermarkGate
 from repro.serving.reactor import EngineReactor, RequestHandle, TokenEvent
 from repro.serving.request import Session, SessionState
-from repro.serving.telemetry import RegistryDict
+from repro.serving.telemetry import RegistryDict, span
 
 # tool_fn(session, completed_turn_idx) -> optional replacement tokens
 # for the *next* turn's prefill (a real tool's output); None keeps the
@@ -307,6 +307,7 @@ class AgentGateway:
             raise GatewayStopped(
                 f"gateway loop stopped; last engine error:\n"
                 f"{self.last_engine_error}")
+        t_staged = self.engine.clock()
         self._kv_pressure_gate()
         occ = self.occupancy()
         if not self.gate.check(occ) and self.cfg.admission == "queue":
@@ -322,6 +323,7 @@ class AgentGateway:
             session.deadline_s = self.engine.clock() + float(rel)
         live = LiveSession(session, self)
         self._live[session.session_id] = live
+        session.staged_s.append(t_staged)
         self._ops.append(("submit", live, None))
         self.counters["submitted"] += 1
         return live
@@ -355,15 +357,17 @@ class AgentGateway:
         errors_in_row = 0
         while self._running or self._ops or self.reactor.pending():
             try:
-                self._drain_ops()
-                self._park_under_pressure()
+                with span("engine.reactor.ops"):
+                    self._drain_ops()
+                    self._park_under_pressure()
                 if cfg.step_in_thread:
                     events = await asyncio.to_thread(self.reactor.step)
                 else:
                     events = self.reactor.step()
                     await asyncio.sleep(0)   # let clients/timers breathe
-                for ev in events:
-                    self._route(ev)
+                with span("engine.reactor.route"):
+                    for ev in events:
+                        self._route(ev)
                 errors_in_row = 0
             except asyncio.CancelledError:
                 raise
@@ -542,6 +546,7 @@ class AgentGateway:
             self._ops.append(("abort", live, "tool_failed"))
             return
         live.state = GatewayState.RESUME
+        sess.staged_s.append(self.engine.clock())
         self._ops.append(("resume", live, None))
 
     # ---- observability -------------------------------------------------

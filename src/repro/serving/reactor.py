@@ -12,7 +12,8 @@ top of the same ``step()``, so the Fig-5 batch path and the online
 gateway dispatch identical cycle code.
 
 ``TokenEvent`` is the engine's emission record: one decoded token for
-one session, stamped with the engine clock.  ``turn_end`` marks the
+one session, stamped with the engine clock — ``t`` when the device made
+it, ``t_emit`` when the host read it.  ``turn_end`` marks the
 last token of a decode burst (the agent is about to leave for a tool
 call — the gateway's TOOL_WAIT trigger) and ``session_end`` the last
 token of the final turn.
@@ -46,6 +47,13 @@ class TokenEvent:
     # distinguish failure from completion; abort_reason attributes it
     error: bool = False
     abort_reason: str = ""
+    # engine-clock time the host read the token: a decode token's flush
+    # (``t`` is interpolated back across the flush window), else ``t``
+    t_emit: Optional[float] = None
+
+    def __post_init__(self):
+        if self.t_emit is None:
+            self.t_emit = self.t
 
 
 class HandleStatus(enum.Enum):
@@ -99,8 +107,8 @@ class EngineReactor:
         """Register a live session.  ``arrival_s`` (engine clock) defaults
         to *now* — the open-loop driver controls offered load by when it
         calls submit, not by pre-staged ``ready_s`` offsets."""
-        session.ready_s = (self.engine.clock() if arrival_s is None
-                           else arrival_s)
+        self.engine.mark_ready(session, self.engine.clock()
+                               if arrival_s is None else arrival_s)
         self.engine.attach(session)
         handle = RequestHandle(session=session)
         self._handles[session.session_id] = handle

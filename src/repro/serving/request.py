@@ -61,6 +61,15 @@ class Session:
     # per-request admission wait (request ready -> admitted), aligned
     # with request_arrivals — the open-loop queue-delay breakdown
     queue_delays_s: List[float] = dataclasses.field(default_factory=list)
+    # per-request stage stamps (engine clock), aligned with
+    # request_arrivals: staged by the gateway (cold: submit entry;
+    # resume: tool return) -> ready (op applied to the engine) ->
+    # admitted (request_arrivals) -> first device call carrying the
+    # prompt -> first token (first_token_s).  Gateway-less sessions
+    # (``ServingEngine.run``) have no staged stamps.
+    staged_s: List[float] = dataclasses.field(default_factory=list)
+    readied_s: List[float] = dataclasses.field(default_factory=list)
+    dispatched_s: List[float] = dataclasses.field(default_factory=list)
 
     @property
     def current_turn(self) -> Optional[AgentTurn]:

@@ -36,6 +36,12 @@ Three coordinated pieces, all allocation-light on the serving hot path:
 Timestamps are engine-clock seconds (``ServingEngine.clock()``)
 throughout, so spans, the cycle trace and the plan journal share one
 timebase.
+
+A fourth, separate piece, ``span()``, puts host spans on the device
+trace's own clock: ``jax.profiler`` annotations around the engine's
+cycle, its host waits on the device and its jitted calls, and the
+gateway's work between cycles.  They record only while a profiler
+session runs (``jax.profiler.start_trace``).
 """
 from __future__ import annotations
 
@@ -47,10 +53,12 @@ import sys
 from typing import Callable, Dict, Iterable, List, Mapping, Optional, \
     Sequence, Tuple
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "RegistryDict",
     "SpanTracer", "Telemetry", "export_trace", "validate_trace_events",
-    "parse_prometheus_text", "reconstruct_latency",
+    "parse_prometheus_text", "reconstruct_latency", "span",
 ]
 
 # default histogram buckets (seconds): sub-ms dispatch gaps up to
@@ -301,6 +309,23 @@ class RegistryDict(collections.abc.MutableMapping):
 
     def __len__(self):
         return len(self._metrics)
+
+
+# ---------------------------------------------------------------------------
+# profiler spans (device trace clock)
+# ---------------------------------------------------------------------------
+
+def span(name: str) -> TraceAnnotation:
+    """A host span on the profiler's clock, as a context manager.
+    Recorded only while a ``jax.profiler`` session runs; otherwise it
+    only builds and drops one small object.  Names start with
+    ``engine.``:
+    ``engine.step/plan/execute/flush`` (the cycle and its phases),
+    ``engine.block`` (a host wait on the device),
+    ``engine.dispatch.decode/prefill/resume`` (a jitted call's host
+    submission) and ``engine.reactor.ops/route`` (gateway work between
+    cycles)."""
+    return TraceAnnotation(name)
 
 
 # ---------------------------------------------------------------------------

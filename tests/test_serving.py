@@ -324,3 +324,31 @@ def test_park_unpark_preserves_resume(tiny_engine_parts):
     for s in sessions:
         assert streams[s.session_id] == want[s.session_id]
     assert all(reactor.poll(h) is HandleStatus.DONE for h in handles)
+
+
+def test_device_mirrors_are_not_host_aliases():
+    """``_sync_device_state`` uploads copies of the host mirrors.  On the
+    CPU ``jnp.asarray`` may alias a small NumPy buffer, and the engine
+    advances ``pool.lengths`` (and the spec history) in place right after
+    dispatching the step that reads them: an aliased upload would hand
+    that step the advanced lengths."""
+    import types
+
+    def aligned(shape):
+        # XLA:CPU aliases host buffers aligned to 64 bytes
+        buf = np.zeros(int(np.prod(shape)) + 64, np.int32)
+        off = (-buf.ctypes.data % 64) // buf.itemsize
+        return buf[off:off + int(np.prod(shape))].reshape(shape)
+    lengths, history = aligned((4,)), aligned((4, 8))
+    lengths[[0, 2]] = 7, 3
+    eng = types.SimpleNamespace(
+        _dev_dirty=True, _dev_ids=[], _window_steps=0, _spec_on=True,
+        _hist_dirty=True, _history=history,
+        ecfg=types.SimpleNamespace(num_slots=4),
+        pool=types.SimpleNamespace(lengths=lengths))
+    active = [types.SimpleNamespace(session_id=0, slot=0, last_token=5)]
+    ServingEngine._sync_device_state(eng, active)
+    lengths += 1000
+    history[0, :3] = 9
+    np.testing.assert_array_equal(np.asarray(eng._dev_lengths), [7, 0, 3, 0])
+    assert not np.asarray(eng._dev_history).any()

@@ -14,7 +14,9 @@ The two load-bearing invariants:
 """
 import asyncio
 import dataclasses
+import glob
 import math
+import os
 
 import jax
 import numpy as np
@@ -285,7 +287,7 @@ def test_normal_run_spans_close_and_latency_reconstructs(
 
     # --- hot-path histograms populated ---------------------------------
     snap = eng.stats()
-    assert snap["ttft_s_count"] >= len(want_ttfts)
+    assert snap["prefill_run_s_count"] >= len(want_ttfts)
     assert snap["dispatch_gap_s_count"] > 0
     assert snap["device_wait_s_count"] > 0
 
@@ -472,3 +474,116 @@ def test_dispatch_gap_excludes_block_time(tiny_params):
     eng._observe_gap(200.1)
     assert hist.sum - base_sum == pytest.approx(0.15)
     assert hist.samples[-1] == 0.0
+
+
+# ---------------------------------------------------------------------------
+# per-request stage stamps, emission stamps, profiler spans
+# ---------------------------------------------------------------------------
+
+def _stages(sess):
+    """Per finished request: (staged, ready, admitted, dispatched,
+    first token)."""
+    n = len(sess.first_token_s)
+    return list(zip(sess.staged_s[:n], sess.readied_s[:n],
+                    sess.request_arrivals[:n], sess.dispatched_s[:n],
+                    sess.first_token_s))
+
+
+@pytest.mark.parametrize("admission", ["reject", "queue"])
+def test_stage_stamps_ordered_and_add_up(tiny_params, admission):
+    """Every finished request of a gateway run carries its five stage
+    stamps in order, and the four parts (gateway, admission, planner,
+    prefill run) add up to staged -> first token.  The stage histograms
+    count one observation per request."""
+    eng = _engine(tiny_params, num_slots=2)
+    gw = AgentGateway(eng, GatewayConfig(high_watermark=32,
+                                         admission=admission))
+    sessions = _sessions(4, rate=16.0)
+    run = _drive(gw, sessions)
+    assert len(run.completed) == 4
+    n_req = 0
+    for sess in run.completed:
+        assert len(sess.staged_s) == len(sess.readied_s) == len(
+            sess.request_arrivals) == len(sess.dispatched_s) == len(
+            sess.first_token_s) == len(sess.turns)
+        for st, rd, adm, disp, first in _stages(sess):
+            assert st <= rd <= adm <= disp <= first
+            parts = (rd - st) + (adm - rd) + (disp - adm) + (first - disp)
+            assert parts == pytest.approx(first - st, abs=1e-9)
+            n_req += 1
+        assert sess.queue_delays_s == pytest.approx(
+            [a - r for a, r in zip(sess.request_arrivals,
+                                   sess.readied_s)])
+    snap = eng.stats()
+    for name in ("gateway_wait_s", "prefill_queue_s", "prefill_run_s"):
+        assert snap[f"{name}_count"] == n_req, name
+    assert snap["token_hold_s_count"] > 0
+
+
+def test_stage_histograms_replace_ttft_tpot(tiny_params):
+    """The stage histograms are on every stats surface; the unread
+    ``ttft_s`` / ``tpot_s`` are gone from the registry and /metrics."""
+    eng = _engine(tiny_params)
+    snap = eng.stats()
+    samples = parse_prometheus_text(eng.telemetry.registry.prometheus_text())
+    for name in ("gateway_wait_s", "prefill_queue_s", "prefill_run_s",
+                 "token_hold_s"):
+        assert f"{name}_count" in snap and f"{name}_count" in samples
+    for gone in ("ttft_s", "tpot_s"):
+        assert f"{gone}_count" not in snap
+        assert f"{gone}_count" not in samples
+
+
+def test_run_without_gateway_has_no_staged_stamps(tiny_params):
+    """The closed-loop ``run()`` stamps ready, admitted, dispatched and
+    first token; it has no gateway, so no staging wait is observed."""
+    eng = _engine(tiny_params)
+    sessions = _sessions(2, rate=16.0)
+    eng.run(sessions)
+    for sess in sessions:
+        assert sess.staged_s == []
+        for rd, adm, disp, first in zip(sess.readied_s,
+                                        sess.request_arrivals,
+                                        sess.dispatched_s,
+                                        sess.first_token_s):
+            assert rd <= adm <= disp <= first
+    assert eng.stats()["gateway_wait_s_count"] == 0
+
+
+def test_token_events_carry_emission_stamp(tiny_params):
+    """``t_emit`` (host read) is never before ``t`` (the interpolated
+    device stamp); a first token is read when it is made."""
+    eng = _engine(tiny_params)
+    gw = AgentGateway(eng, GatewayConfig(high_watermark=32))
+    run = _drive(gw, _sessions(3, rate=16.0))
+    evs = [ev for _, ev in run.events]
+    assert evs and any(not ev.first for ev in evs)
+    for ev in evs:
+        assert ev.t_emit >= ev.t
+        if ev.first:
+            assert ev.t_emit == ev.t
+    # a decode window's tokens share one host read
+    assert any(ev.t_emit > ev.t for ev in evs if not ev.first)
+
+
+def test_profiler_trace_holds_program_spans(tiny_params, tmp_path):
+    """A profiler trace of a gateway stepping, with nothing wrapped from
+    outside, holds the program's own host spans."""
+    from jax.profiler import ProfileData
+    eng = _engine(tiny_params)
+    gw = AgentGateway(eng, GatewayConfig(high_watermark=32))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        run = _drive(gw, _sessions(2, rate=16.0))
+    finally:
+        jax.profiler.stop_trace()
+    assert len(run.completed) == 2
+    files = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    names = {e.name for plane in ProfileData.from_file(files[-1]).planes
+             for line in plane.lines for e in line.events}
+    for want in ("engine.step", "engine.plan", "engine.execute",
+                 "engine.flush", "engine.block", "engine.reactor.ops",
+                 "engine.reactor.route", "engine.dispatch.decode",
+                 "engine.dispatch.prefill"):
+        assert want in names, want
