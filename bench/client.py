@@ -18,7 +18,7 @@ class Stream:
     status: int = 0                          # HTTP status; 0 = none yet
     events: List[Dict] = dataclasses.field(default_factory=list)
     received_s: List[float] = dataclasses.field(default_factory=list)
-    end: str = ""                            # "done" | "aborted" | ""
+    end: str = ""                    # "done" | "aborted" | "cut" | ""
 
     @property
     def session_id(self) -> Optional[int]:

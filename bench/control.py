@@ -11,7 +11,8 @@ traffic.  Each run prints one JSON line with the gap readings of its
 served tokens against the float32 reference.  For the float32 runs the
 line also gives the readings of the reference itself, computed at each
 of ``correctness.CONTROLS`` (int8 weights, bfloat16, float32 at default
-matmul precision) and put in the program's place on the same context.
+matmul precision, and for a configuration with q/k/v biases float32
+without them) and put in the program's place on the same context.
 The benchmark's own runs never run this.
 """
 from __future__ import annotations

@@ -25,8 +25,9 @@ from bench.traffic import SessionPlan
 # sessions compared per run: the longest, then others from the seed
 MAX_SESSIONS = 8
 # the reference at these numerics is put in the program's place for
-# the readings a limit is set from (``bench/control.py``)
-CONTROLS = ("int8", "bfloat16", "float32_default")
+# the readings a limit is set from (``bench/control.py``); the last
+# only for a configuration with q/k/v biases, which it leaves out
+CONTROLS = ("int8", "bfloat16", "float32_default", "float32_nobias")
 
 
 @dataclasses.dataclass
@@ -102,6 +103,12 @@ def control_gaps(params: Dict, cfg: Dict, samples: Sequence[Sample],
     return np.concatenate(out)
 
 
+def controls(cfg: Dict) -> Tuple[str, ...]:
+    """The controls that apply to the configuration."""
+    return tuple(c for c in CONTROLS
+                 if c != "float32_nobias" or cfg.get("qkv_bias", False))
+
+
 def gap_readings(gaps: np.ndarray) -> Dict[str, float]:
     """The widest gap, the mean gap, and the share of tokens that are
     not the reference's first choice."""
@@ -139,7 +146,7 @@ def check(cell, obs, plans: Dict[int, SessionPlan], seed: int,
     readings = gap_readings(gaps)
     gap, compared = readings["max_logit_gap"], len(gaps)
     control = {}
-    for numerics in CONTROLS if reference_control else ():
+    for numerics in controls(cell.config) if reference_control else ():
         control.update({f"ref_{numerics}_{k}": v for k, v in gap_readings(
             control_gaps(params, cell.config, samples, pad_to,
                          numerics)).items()})

@@ -4,7 +4,8 @@ Counts are the model's own (what a dense GQA block needs), not what an
 executable happens to run: padding, masked rows and the unused part of
 a slot are not counted.  Weights and the KV cache are float32, the
 precision the program serves.  ``cfg`` is a configuration dict
-(``bench/configs/*.json``).
+(``bench/configs/*.json``); with ``qkv_bias`` the q/k/v biases count,
+their bytes once per step and one add per element per token.
 """
 from __future__ import annotations
 
@@ -31,10 +32,18 @@ def _dims(cfg: Dict):
             cfg["head_dim"], cfg["intermediate_size"], cfg["vocab_size"])
 
 
+def _bias_width(cfg: Dict) -> int:
+    """Elements of one layer's q/k/v biases (0 without them)."""
+    _, _, H, Hk, hd, _, _ = _dims(cfg)
+    return (H * hd + 2 * Hk * hd) if cfg.get("qkv_bias", False) else 0
+
+
 def layer_matmul_flops(cfg: Dict) -> int:
-    """One token through every layer's projections and MLP."""
+    """One token through every layer's projections (their biases'
+    adds included) and MLP."""
     L, d, H, Hk, hd, F, _ = _dims(cfg)
-    return 2 * L * (d * H * hd + 2 * d * Hk * hd + H * hd * d + 3 * d * F)
+    return 2 * L * (d * H * hd + 2 * d * Hk * hd + H * hd * d + 3 * d * F) \
+        + L * _bias_width(cfg)
 
 
 def logits_flops(cfg: Dict) -> int:
@@ -66,7 +75,8 @@ def prefill_flops(cfg: Dict, tokens: int, context: int) -> int:
 
 def param_bytes(cfg: Dict) -> int:
     L, d, H, Hk, hd, F, V = _dims(cfg)
-    per_layer = 2 * d + d * (2 * H * hd + 2 * Hk * hd) + 3 * d * F
+    per_layer = 2 * d + d * (2 * H * hd + 2 * Hk * hd) + 3 * d * F \
+        + _bias_width(cfg)
     return F32 * (L * per_layer + V * d + d)
 
 

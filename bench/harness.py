@@ -21,7 +21,7 @@ import json
 import threading
 import time
 from pathlib import Path
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Awaitable, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -216,7 +216,28 @@ class _GapWindow:
             self.samples = xs[len(xs) - min(n, len(xs)):] if n else []
 
 
-async def drive(srv: Server, schedule: List[tr.Arrival],
+async def run_agent(pool: List[int], at: float, close_s: float,
+                    serve: Callable[[int, float], Awaitable],
+                    clock: Callable[[], float]) -> None:
+    """One closed-loop agent: serve the sessions of ``pool`` in turn,
+    the first due at ``at`` and each next one due when the previous
+    stream has ended, however it ended; none starts at or after
+    ``close_s``."""
+    for spec_seed in pool:
+        if at >= close_s:
+            return
+        try:
+            await serve(spec_seed, at)
+        except Exception:   # the stream, cut or unread, counts as failed
+            pass
+        at = clock()
+    if at < close_s:
+        raise tr.PoolExhausted(f"a closed-loop agent ran through its "
+                               f"{len(pool)} sessions before the window "
+                               f"closed")
+
+
+async def drive(srv: Server, schedule: tr.Schedule,
                 plans: Dict[int, tr.SessionPlan], seconds: float,
                 trace_dir: Optional[str] = None,
                 on_open: Optional[Callable[[], None]] = None) -> Observed:
@@ -250,12 +271,22 @@ async def drive(srv: Server, schedule: List[tr.Arrival],
         delay = at - clock()
         if delay > 0:
             await asyncio.sleep(delay)
-        return await submit(HOST, port, tr.request_spec(mix, spec_seed), st)
+        try:
+            return await submit(HOST, port, tr.request_spec(mix, spec_seed),
+                                st)
+        except Exception:
+            st.end = "cut"      # nothing more comes: not pending, failed
+            raise
 
-    for a in schedule:
-        if t_start + a.at_s < close_s:
-            tasks.append(asyncio.create_task(
-                one(a.spec_seed, t_start + a.at_s)))
+    if isinstance(schedule, tr.ClosedLoop):
+        tasks += [asyncio.create_task(run_agent(
+            pool, t_start + first, close_s, one, clock))
+            for first, pool in zip(schedule.first_s, schedule.pools)]
+    else:
+        for a in schedule:
+            if t_start + a.at_s < close_s:
+                tasks.append(asyncio.create_task(
+                    one(a.spec_seed, t_start + a.at_s)))
 
     await asyncio.sleep(max(0.0, open_s - clock()))
     if on_open is not None:
@@ -285,9 +316,12 @@ async def drive(srv: Server, schedule: List[tr.Arrival],
         await asyncio.wait(live, timeout=STOP_S)
     for t in tasks:
         t.cancel()
-    await asyncio.gather(*tasks, return_exceptions=True)
+    ended = await asyncio.gather(*tasks, return_exceptions=True)
     server.close()
     await server.wait_closed()
+    for e in ended:
+        if isinstance(e, tr.PoolExhausted):
+            raise e
     sessions = {s.session_id: s for s in
                 list(gateway.completed_sessions)
                 + list(gateway.failed_sessions)}

@@ -9,6 +9,14 @@ Weights are the benchmark's, not the program's: one jitted call makes
 them on the device from ``--seed``, in the layout the program's model
 takes, and :mod:`bench.reference` rebuilds the same arrays from the
 same seed for its own forward pass.
+
+``"qkv_bias": true`` (absent means false; Qwen2's attention has the
+biases by construction, so its ``config.json`` names no such key) adds
+biases to the q, k and v projections: ``bq`` ``[L, H * hd]``, ``bk``
+and ``bv`` ``[L, Hk * hd]`` under ``groups.l0.attn``, drawn at
+:data:`BIAS_SCALE` from a key of their own, so a file without the key
+gets the same weights as before, bit for bit.  The program takes them
+as ``ModelConfig.qkv_bias``.
 """
 from __future__ import annotations
 
@@ -22,6 +30,10 @@ import jax
 import jax.numpy as jnp
 
 CONFIG_DIR = Path(__file__).resolve().parent / "configs"
+# standard deviation of the seeded q/k/v biases: large enough that the
+# reference with the biases left out reads far above a configuration's
+# ``max_logit_gap`` limit (PERF.md gives the readings)
+BIAS_SCALE = 1.0
 
 # published config.json key -> the program's ModelConfig field
 FIELDS = {
@@ -63,6 +75,13 @@ def model_config(cfg: Dict):
     over["rope_theta"] = float(over["rope_theta"])
     over["norm_eps"] = float(over["norm_eps"])
     over["kv_layout"] = cfg["engine"].get("kv_layout", "slab")
+    if cfg.get("qkv_bias", False):
+        if "qkv_bias" not in {f.name for f in dataclasses.fields(base)}:
+            raise ValueError(
+                f"{cfg['arch']}: the configuration sets qkv_bias, and the "
+                f"program's ModelConfig has no field 'qkv_bias' to serve "
+                f"the q/k/v projection biases (bq, bk, bv) with")
+        over["qkv_bias"] = True
     return dataclasses.replace(base, **over)
 
 
@@ -79,8 +98,8 @@ def _shapes(cfg: Dict) -> Dict:
     return {"L": L, "d": d, "q": H * hd, "kv": Hk * hd, "F": F, "V": V}
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _make(key, dims: tuple, dtype):
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _make(key, dims: tuple, dtype, qkv_bias: bool):
     s = dict(dims)
     L, d, q, kv, F, V = s["L"], s["d"], s["q"], s["kv"], s["F"], s["V"]
     ks = iter(jax.random.split(key, 12))
@@ -104,6 +123,11 @@ def _make(key, dims: tuple, dtype):
                 "w_up": dense(next(ks), d, (L, d, F)),
                 "w_down": dense(next(ks), F, (L, F, d))},
     }
+    if qkv_bias:        # a key apart from the 12 above: they stay as drawn
+        kb = jax.random.split(jax.random.fold_in(key, 0x62696173), 3)
+        for k, name, width in zip(kb, ("bq", "bk", "bv"), (q, kv, kv)):
+            layer["attn"][name] = (BIAS_SCALE * jax.random.normal(
+                k, (L, width), jnp.float32)).astype(dtype)
     return {"embed": (0.02 * jax.random.normal(next(ks), (V, d),
                                                jnp.float32)).astype(dtype),
             "groups": {"l0": layer},
@@ -120,5 +144,6 @@ def make_params(cfg: Dict, seed: int, dtype=jnp.float32) -> Dict:
         raise ValueError("vocab must be a multiple of 128 (the program "
                          "pads the embedding table to one)")
     dims = tuple(sorted(_shapes(cfg).items()))
-    return _make(seed_key(seed), dims, jnp.dtype(dtype))
+    return _make(seed_key(seed), dims, jnp.dtype(dtype),
+                 bool(cfg.get("qkv_bias", False)))
 

@@ -2,15 +2,14 @@
 
 Follows the published Llama/Qwen2 block: RMSNorm, rotary embeddings
 (rotate-half), grouped-query causal attention, SwiGLU MLP, pre-norm
-residuals, tied output embedding.  Float32 at ``highest`` matmul
-precision; the other entries of :data:`NUMERICS` run the same
-equations at lower precision, for the controls.  It imports nothing
-of the program: the weights come from :func:`bench.model.make_params`
-with the run's seed, and it runs one layer at a time, with queries in
-blocks, so that a whole session's context fits beside the weights.
-
-Departures from the published models: Qwen2.5's q/k/v projection
-biases are absent (the served program has none).
+residuals, tied output embedding; where the weights hold q/k/v biases
+(Qwen2), they are added right after the projections, before the
+rotary embeddings.  Float32 at ``highest`` matmul precision; the other
+entries of :data:`NUMERICS` run the same equations at lower precision,
+or without the biases, for the controls.  It imports nothing of the
+program: the weights come from :func:`bench.model.make_params` with the
+run's seed, and it runs one layer at a time, with queries in blocks, so
+that a whole session's context fits beside the weights.
 """
 from __future__ import annotations
 
@@ -25,13 +24,16 @@ Q_BLOCK = 512
 ROW_BLOCK = 256
 
 # name -> (activation and weight dtype, matmul precision, weights
-# rounded to int8 with one scale per output channel)
+# rounded to int8 with one scale per output channel, q/k/v biases left
+# out)
 NUMERICS = {
-    "float32": (jnp.float32, "highest", False),        # the reference
-    "float32_default": (jnp.float32, "default", False),
-    "bfloat16": (jnp.bfloat16, "default", False),
-    "int8": (jnp.bfloat16, "default", True),
+    "float32": (jnp.float32, "highest", False, False),  # the reference
+    "float32_default": (jnp.float32, "default", False, False),
+    "bfloat16": (jnp.bfloat16, "default", False, False),
+    "int8": (jnp.bfloat16, "default", True, False),
+    "float32_nobias": (jnp.float32, "highest", False, True),
 }
+BIASES = ("bq", "bk", "bv")
 
 
 def _int8(w, axis):
@@ -69,9 +71,13 @@ def _layer(x, lp, *, dims, dtype, prec):
     G = H // Hk
     mm = functools.partial(jnp.matmul, precision=prec)
     h = _rms_norm(x, lp["norm1"], eps)
-    q = _rope(mm(h, lp["wq"]).reshape(T, H, hd), theta)
-    k = _rope(mm(h, lp["wk"]).reshape(T, Hk, hd), theta)
-    v = mm(h, lp["wv"]).reshape(T, Hk, hd)
+
+    def proj(w, b):
+        y = mm(h, lp[w])
+        return y + lp[b] if b in lp else y
+    q = _rope(proj("wq", "bq").reshape(T, H, hd), theta)
+    k = _rope(proj("wk", "bk").reshape(T, Hk, hd), theta)
+    v = proj("wv", "bv").reshape(T, Hk, hd)
     qb = q.reshape(T // Q_BLOCK, Q_BLOCK, Hk, G, hd)
     scale = 1.0 / np.sqrt(hd)
 
@@ -102,19 +108,23 @@ def _logit_stats(h, embed, targets, *, prec):
 
 
 def _embed(params: Dict, numerics: str):
-    dtype, _, int8 = NUMERICS[numerics]
+    dtype, _, int8, _ = NUMERICS[numerics]
     e = params["embed"]
     return (_int8(e, 1) if int8 else e).astype(dtype)
 
 
 def _layer_params(params: Dict, i: int, numerics: str) -> Dict:
-    dtype, _, int8 = NUMERICS[numerics]
+    """Layer ``i``'s weights at ``numerics``; biases are never rounded
+    to int8."""
+    dtype, _, int8, no_bias = NUMERICS[numerics]
     g = params["groups"]["l0"]
 
     def w(x):
         return (_int8(x[i], 0) if int8 else x[i]).astype(dtype)
-    return {"norm1": g["norm1"][i], "norm2": g["norm2"][i],
-            **{k: w(x) for k, x in g["attn"].items()},
+    attn = {k: (x[i].astype(dtype) if k in BIASES else w(x))
+            for k, x in g["attn"].items()
+            if not (no_bias and k in BIASES)}
+    return {"norm1": g["norm1"][i], "norm2": g["norm2"][i], **attn,
             **{k: w(x) for k, x in g["ffn"].items()}}
 
 
@@ -122,7 +132,7 @@ def final_hidden(params: Dict, cfg: Dict, tokens: np.ndarray,
                  pad_to: int, numerics: str = "float32") -> jax.Array:
     """Normalised last-layer states [pad_to, d] of ``tokens`` (padded at
     the end, which causal attention keeps out of the real rows)."""
-    dtype, prec, _ = NUMERICS[numerics]
+    dtype, prec, _, _ = NUMERICS[numerics]
     T = len(tokens)
     pad = -(-max(pad_to, T) // Q_BLOCK) * Q_BLOCK
     toks = np.zeros((pad,), np.int32)
@@ -141,7 +151,7 @@ def logit_stats(params: Dict, h: jax.Array, rows: Sequence[int],
                 targets: Sequence[int], numerics: str = "float32",
                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """(best, argmax, logit of target) at the given rows of ``h``."""
-    dtype, prec, _ = NUMERICS[numerics]
+    dtype, prec, _, _ = NUMERICS[numerics]
     embed = _embed(params, numerics)
     rows, targets = np.asarray(rows, np.int32), np.asarray(targets,
                                                            np.int32)

@@ -38,6 +38,15 @@ def backlog(obs, t: float) -> int:
     return n
 
 
+def refuse_closed(mix) -> None:
+    """A closed mix sets its own load by its number of agents: it has
+    no rate to sweep."""
+    if mix["arrivals"]["kind"] != "poisson":
+        raise SystemExit(f"bench/sweep.py: a {mix['arrivals']['kind']} "
+                         f"mix has no session rate to sweep; its load is "
+                         f"its number of agents")
+
+
 def main(argv=None) -> int:
     import numpy as np
 
@@ -49,6 +58,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", default="0")
     args = ap.parse_args(argv)
     cell = harness.load_cell(args.workload)
+    refuse_closed(cell.mix)
     run.check_device(cell.chips)
     run.enable_cache()
     runs = [(float(r), int(s)) for r in args.rates.split(",")

@@ -35,3 +35,13 @@ def test_peaks_known_and_unknown():
     assert pk["flops_per_s"] == 197e12 and pk["hbm_bytes_per_s"] == 819e9
     with pytest.raises(KeyError):
         cost.peaks("TPU v9 imaginary")
+
+
+def test_qkv_bias_counts_by_hand():
+    cfg = {**CFG, "qkv_bias": True}
+    # per layer: q 8, k and v 4 each -> 16 bias elements, one add each
+    assert cost.layer_matmul_flops(cfg) == 2 * 2 * 576 + 2 * 16
+    assert cost.decode_token_flops(cfg, 10) == 2336 + 640 + 2048
+    per_layer = 2 * 8 + 8 * (2 * 8 + 2 * 4) + 3 * 8 * 16 + 16
+    assert cost.param_bytes(cfg) == 4 * (2 * per_layer + 128 * 8 + 8)
+    assert cost.kv_row_bytes(cfg) == cost.kv_row_bytes(CFG)

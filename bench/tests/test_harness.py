@@ -38,6 +38,21 @@ def test_benchmark_cells_resolve_by_name():
         assert (ROOT / c["file"]).is_file()
 
 
+def test_output_tok_s_is_reported_only_where_listed():
+    """``output_tok_s`` is reported only by the cells its entry lists:
+    the open-loop ReAct cell, where it would read the offered load,
+    keeps its three metrics, and a closed-loop cell reports it once an
+    entry of its own names it, as the fixture's does."""
+    cell = harness.load_cell("smollm-360m.react.steady")
+    assert [m["name"] for m in cell.end_to_end] == \
+        ["ttft_p90_ms", "tpot_mean_ms", "setup_s"]
+    closed = {m["name"]: m for m in _cell("tiny.plan.closed").end_to_end}
+    entry = closed["output_tok_s"]
+    assert entry["unit"] == "tok/s" and entry["better"] == "higher"
+    assert entry["workloads"] == ["tiny.plan.closed"]
+    assert "output_tok_s" not in [m["name"] for m in _cell().end_to_end]
+
+
 @pytest.mark.parametrize("lookup", [
     lambda: harness.load_cell("no.such.cell"),
     lambda: model.load_config("no-such-model"),
@@ -112,13 +127,18 @@ def test_schedule_is_the_mixes_own(seed):
 
 def test_session_plan_matches_what_the_server_builds():
     """The benchmark's copy of the session generator draws exactly what
-    the server draws for the same spec seed."""
+    the server draws for the same spec seed, for ReAct and for
+    Plan-and-Execute."""
     from repro.launch.serve import _session_from_spec
     from repro.configs.base import get_config
-    mix = json.loads((ROOT / "bench" / "traffic" /
-                      "react_steady.json").read_text())
+    react = json.loads((ROOT / "bench" / "traffic" /
+                        "react_steady.json").read_text())
+    plan_exec = {**json.loads((FIX / "traffic" /
+                               "tiny_plan_closed.json").read_text()),
+                 "token_scale": 1.0}
     mcfg = get_config("smollm-360m")
-    for seed in (100000, 100003, 2 ** 31 + 9):
+    for mix, seed in ((m, s) for m in (react, plan_exec)
+                      for s in (100000, 100003, 2 ** 31 + 9)):
         spec = tr.request_spec(mix, seed)
         sess = _session_from_spec(spec, mcfg, 1.0, 1 << 20)
         plan = tr.make_session(mix["paradigm"], seed, mcfg.vocab_size,
@@ -184,3 +204,19 @@ def test_slot_occupancy_is_weighted_by_time():
     rec = type("R", (), {"obs": obs, "num_slots": 6})()
     got = run.metric_reader("slot_occupancy_pct")(rec)
     assert got == pytest.approx(100.0 * (0.01 + 9.0) / 10.0)
+
+
+@pytest.mark.parametrize("end, pending", [("", True), ("cut", False)])
+def test_a_cut_stream_is_failed_and_not_waited_for(end, pending):
+    """A stream whose client broke off (``end == "cut"``) holds up no
+    drain, and its unfinished turn counts as failed."""
+    from bench.client import Stream
+    mix = tr.load_mix("tiny_react", FIX / "traffic")
+    plans = tr.session_plans(mix, [tr.Arrival(1.0, 7)], 512)
+    st = Stream(7, status=200, end=end)
+    assert harness._pending([st], [1.0], {}, plans, 0.0, 10.0) is pending
+    obs = type("O", (), {"open_s": 0.0, "close_s": 10.0, "streams": [st],
+                         "due_cold": [1.0], "tool_done": {}})()
+    reqs, refused, bad = harness.requests_in_window(obs, plans)
+    assert (len(reqs), refused, bad) == (1, 0, [])
+    assert reqs[0].failed and reqs[0].first_s is None

@@ -19,8 +19,8 @@ FIX = Path(__file__).resolve().parent / "fixtures"
 SECONDS = 3.0
 
 
-def _serve(seed, **kw):
-    cell = harness.load_cell("tiny.react", FIX / "BENCHMARK.json",
+def _serve(seed, workload="tiny.react", **kw):
+    cell = harness.load_cell(workload, FIX / "BENCHMARK.json",
                              FIX / "configs", FIX / "traffic")
     args = argparse.Namespace(workload=cell.name, seed=seed,
                               seconds=SECONDS, trace=0, keep_trace="")
@@ -57,6 +57,18 @@ def test_served_cell_is_correct(sound):
     assert names == {"ttft_p90_ms", "tpot_mean_ms", "setup_s"}
     assert all(v["value"] > 0 for v in sound["metrics"].values())
     assert list(sound)[-1] == "checks"
+
+
+def test_closed_loop_cell_is_correct():
+    """Six closed-loop agents of Plan-and-Execute sessions: every
+    request due in the window is served and correct, and the window's
+    tokens per second are reported."""
+    res = _serve(2 ** 32 + 13, workload="tiny.plan.closed")
+    assert res["correct"], res["checks"]
+    assert res["failed"] == 0
+    assert res["attempted"] > 0
+    assert res["metrics"]["output_tok_s"]["value"] > 0
+    assert res["metrics"]["output_tok_s"]["unit"] == "tok/s"
 
 
 @pytest.mark.parametrize("fault", ["altered_token", "bf16_control"])
