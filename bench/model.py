@@ -16,7 +16,8 @@ biases to the q, k and v projections: ``bq`` ``[L, H * hd]``, ``bk``
 and ``bv`` ``[L, Hk * hd]`` under ``groups.l0.attn``, drawn at
 :data:`BIAS_SCALE` from a key of their own, so a file without the key
 gets the same weights as before, bit for bit.  The program takes them
-as ``ModelConfig.qkv_bias``.
+as ``ModelConfig.qkv_bias``, set from the file both ways wherever the
+program has that field.
 """
 from __future__ import annotations
 
@@ -66,7 +67,9 @@ def load_config(name: str, config_dir: Path = CONFIG_DIR) -> Dict:
 
 def model_config(cfg: Dict):
     """The program's ``ModelConfig``: its registry entry with every size
-    set from the file, so what is served is what the file says."""
+    set from the file, so what is served is what the file says.  So is
+    ``qkv_bias`` (absent means false) where the program has the field;
+    where it has none, a file that asks for the biases is refused."""
     from repro.configs.base import get_config
     base = get_config(cfg["arch"])
     if base.family != "dense":
@@ -75,13 +78,14 @@ def model_config(cfg: Dict):
     over["rope_theta"] = float(over["rope_theta"])
     over["norm_eps"] = float(over["norm_eps"])
     over["kv_layout"] = cfg["engine"].get("kv_layout", "slab")
-    if cfg.get("qkv_bias", False):
-        if "qkv_bias" not in {f.name for f in dataclasses.fields(base)}:
-            raise ValueError(
-                f"{cfg['arch']}: the configuration sets qkv_bias, and the "
-                f"program's ModelConfig has no field 'qkv_bias' to serve "
-                f"the q/k/v projection biases (bq, bk, bv) with")
-        over["qkv_bias"] = True
+    bias = bool(cfg.get("qkv_bias", False))
+    if "qkv_bias" in {f.name for f in dataclasses.fields(base)}:
+        over["qkv_bias"] = bias     # the file's, whatever the registry's
+    elif bias:
+        raise ValueError(
+            f"{cfg['arch']}: the configuration sets qkv_bias, and the "
+            f"program's ModelConfig has no field 'qkv_bias' to serve "
+            f"the q/k/v projection biases (bq, bk, bv) with")
     return dataclasses.replace(base, **over)
 
 

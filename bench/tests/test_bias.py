@@ -2,9 +2,11 @@
 and its control, on the CPU at toy widths: a configuration without them
 draws today's weights bit for bit, the reference with them agrees with
 an independent float64 NumPy forward, the reference with them left out
-reads above the limit, and the program is asked for them by name."""
+reads above the limit, and the program is asked for them by name and
+given them exactly as the file says."""
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import json
 from pathlib import Path
@@ -133,8 +135,52 @@ def test_dropped_bias_control_reads_above_the_limit():
     assert correctness.gap_readings(same)["max_logit_gap"] <= limit
 
 
-def test_model_config_asks_the_program_for_qkv_bias():
-    with pytest.raises(ValueError, match="no field 'qkv_bias'"):
-        model.model_config(_cfg("tiny_bias"))
-    assert model.model_config({**_cfg("tiny_bias"),
-                               "qkv_bias": False}).num_kv_heads == 1
+@dataclasses.dataclass(frozen=True)
+class _Registry:
+    """A dense registry entry as the program's ``get_config`` gives one,
+    without a ``qkv_bias`` field."""
+    name: str = "stand-in"
+    family: str = "dense"
+    num_layers: int = 36
+    d_model: int = 2048
+    num_heads: int = 16
+    num_kv_heads: int = 2
+    d_ff: int = 11008
+    vocab_size: int = 151936
+    head_dim: int = 128
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-6
+    tie_embeddings: bool = True
+    kv_layout: str = "slab"
+
+
+@dataclasses.dataclass(frozen=True)
+class _RegistryWithBias(_Registry):
+    """The same entry once the program serves the biases; the registry
+    holds them by construction, as Qwen2's does."""
+    qkv_bias: bool = True
+
+
+# (the program has the field, what the file says: None is silent)
+@pytest.mark.parametrize("has_field,says", [
+    (False, True), (True, True), (True, None), (True, False),
+    (False, False), (False, None)])
+def test_model_config_asks_the_program_for_qkv_bias(monkeypatch, has_field,
+                                                     says):
+    from repro.configs import base
+    entry = _RegistryWithBias() if has_field else _Registry()
+    monkeypatch.setattr(base, "get_config", lambda arch: entry)
+    cfg = {k: v for k, v in _cfg("tiny_bias").items() if k != "qkv_bias"}
+    if says is not None:
+        cfg["qkv_bias"] = says
+    if says and not has_field:
+        with pytest.raises(ValueError, match="no field 'qkv_bias'"):
+            model.model_config(cfg)
+        return
+    got = model.model_config(cfg)
+    # every size comes from the file, not from the registry
+    assert (got.num_kv_heads, got.d_model, got.num_layers) == (1, 64, 2)
+    if has_field:   # the file's value both ways; silent means no biases
+        assert got.qkv_bias is bool(says)
+    else:
+        assert not hasattr(got, "qkv_bias")
